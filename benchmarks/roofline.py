@@ -39,8 +39,11 @@ ICI_BW = 50e9
 
 _SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
+# the child runs on host devices only: an attached chip belongs to the
+# process that drives it
 SNIPPET = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json, sys
 from repro.launch.dryrun import lower_program

@@ -1,7 +1,8 @@
 """Benchmark runner — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (one per measurement) plus
-PASS/FAIL rows for each of the paper's qualitative claims. Every suite
+PASS/FAIL rows for each of the paper's qualitative claims. Exits 1 when a
+suite raises or a claim FAILs. Every suite
 shares the uniform ``run(quick=..., json_path=...)`` signature; pass
 ``--json-dir`` to write one JSON artifact per suite next to the CSV
 stream.
@@ -19,7 +20,7 @@ import sys
 import time
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced configs (smoke models, fewer steps)")
@@ -96,7 +97,8 @@ def main(argv=None) -> None:
                       f"useful={r['useful_flops_ratio']}")
 
     print(f"claims_failed,{failures},{'OK' if failures == 0 else 'CHECK'}")
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -86,7 +86,7 @@ def _sweep(ms, quick: bool) -> dict:
     from repro.utils.sharding import client_sharding
 
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        enable_compilation_cache(os.environ["JAX_COMPILATION_CACHE_DIR"])
+        enable_compilation_cache()
 
     # ONE model for the whole sweep: M enters only through state/batch
     # shapes, so the scan kernels' (model, chunk, opt) cache key is stable

@@ -149,19 +149,11 @@ def _data_path_cell(cfg, quick: bool) -> dict:
 
 
 def run(quick: bool = True, json_path: str | None = None) -> dict:
-    import os
-    import tempfile
-
     from repro.utils.jit_cache import enable_compilation_cache
 
-    # a persistent compile cache (CI's dir when provided, else a stable
-    # per-user temp dir reused across invocations): the warmup run
-    # populates it, every timed run hits it
-    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or os.path.join(tempfile.gettempdir(),
-                                 "repro-throughput-jit-cache"))
-    os.makedirs(cache_dir, exist_ok=True)
-    enable_compilation_cache(cache_dir)
+    # persistent compile cache: the warmup run populates it, every timed
+    # run hits it
+    enable_compilation_cache()
 
     cfg = get_config("paper-mlp", smoke=True)
     model = build_model(cfg)

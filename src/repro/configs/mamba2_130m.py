@@ -3,6 +3,11 @@
 [arXiv:2405.21060]
 24L d_model=768, ssm_state=128, d_inner=2*768=1536, headdim=64 (24 ssm heads),
 vocab=50280. Sub-quadratic -> runs long_500k (O(1) decode state).
+
+Per-chip sizing: one TPU v5e chip (15.75 GB) holds an MTSL round of 8
+clients at these widths (4 x 512 tokens per client, AdamW: 6.07 GiB state
++ 7.68 GiB temporaries by the compiler's count); num_clients=16 does not
+fit one chip (chip_smoke.py runs the 8-client cut).
 """
 from repro.configs.base import ModelConfig, register
 

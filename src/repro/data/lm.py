@@ -11,6 +11,15 @@ shared chain and a client-private chain:
 beta plays the role of the paper's heterogeneity (beta=0 -> i.i.d. clients;
 beta=1 -> fully disjoint structure). A bigram model can reach the entropy
 floor, so loss curves are meaningful.
+
+The chains run over S = min(vocab_size, MAX_STATES) states, so host memory
+and draw time stay bounded at a real vocabulary (dense V x V chains at
+V=50,280 would be ~20 GB each). When S == vocab_size the states ARE the
+tokens (the historical seeded stream). Otherwise a seeded permutation cuts
+the vocabulary into S near-equal groups and each state emits one token of
+its group uniformly: the token stream is still a first-order Markov chain
+(a token names its state), it covers the whole vocabulary, and beta keeps
+its meaning on the state chain.
 """
 from __future__ import annotations
 
@@ -25,6 +34,10 @@ def _random_transition(rng: np.random.Generator, vocab: int, concentration=0.3):
     return p
 
 
+# S x S float64 chains of 8 MiB each, whatever the vocabulary
+MAX_STATES = 1024
+
+
 @dataclass
 class MultiTaskLMSource:
     vocab_size: int = 256
@@ -34,30 +47,44 @@ class MultiTaskLMSource:
 
     def __post_init__(self):
         rng = np.random.default_rng(self.seed)
-        shared = _random_transition(rng, self.vocab_size)
+        S = self.num_states = min(self.vocab_size, MAX_STATES)
+        shared = _random_transition(rng, S)
         self.chains = []
         for _ in range(self.num_clients):
-            private = _random_transition(rng, self.vocab_size)
+            private = _random_transition(rng, S)
             p = (1 - self.beta) * shared + self.beta * private
             self.chains.append(p / p.sum(axis=1, keepdims=True))
+        if S < self.vocab_size:
+            # state s emits a token of perm[bounds[s]:bounds[s+1]]
+            self._perm = rng.permutation(self.vocab_size)
+            self._bounds = np.arange(S + 1) * self.vocab_size // S
+
+    def _emit(self, rng: np.random.Generator, states):
+        """Tokens for a [..., seq] state array (identity when S == V)."""
+        if self.num_states == self.vocab_size:
+            return states
+        lo = self._bounds[states]
+        width = self._bounds[states + 1] - lo
+        pick = (rng.random(states.shape) * width).astype(np.int64)
+        return self._perm[lo + np.minimum(pick, width - 1)]
 
     def client_tokens(self, rng: np.random.Generator, client: int, batch: int, seq: int):
         P = self.chains[client]
+        S = self.num_states
         cum = np.cumsum(P, axis=1)
         out = np.empty((batch, seq), np.int64)
-        state = rng.integers(0, self.vocab_size, size=batch)
+        state = rng.integers(0, S, size=batch)
         out[:, 0] = state
         for t in range(1, seq):
             u = rng.random(batch)
             # clamp the inverse-CDF draw: fp rounding can leave cum's last
-            # column below 1.0, and a u above it would yield state ==
-            # vocab_size — an out-of-range token that IndexErrors cum[state]
-            # on the next step (the clamp only fires on that overflow, so
-            # existing seeded streams are unchanged)
-            state = np.minimum((cum[state] < u[:, None]).sum(axis=1),
-                               self.vocab_size - 1)
+            # column below 1.0, and a u above it would yield state == S —
+            # out of range, an IndexError at cum[state] on the next step
+            # (the clamp only fires on that overflow, so existing seeded
+            # streams are unchanged)
+            state = np.minimum((cum[state] < u[:, None]).sum(axis=1), S - 1)
             out[:, t] = state
-        return out
+        return self._emit(rng, out)
 
     def all_clients_batch(self, rng: np.random.Generator, batch_per_client: int,
                           seq: int, vectorized: bool = False):
@@ -76,26 +103,29 @@ class MultiTaskLMSource:
                     for m in range(self.num_clients)
                 ]
             )
-        M, V, b = self.num_clients, self.vocab_size, batch_per_client
-        cums = np.cumsum(np.stack(self.chains), axis=2)  # [M, V, V]
+        M, S, b = self.num_clients, self.num_states, batch_per_client
+        cums = np.cumsum(np.stack(self.chains), axis=2)  # [M, S, S]
         out = np.empty((M, b, seq), np.int64)
-        state = rng.integers(0, V, size=(M, b))
+        state = rng.integers(0, S, size=(M, b))
         out[..., 0] = state
         midx = np.arange(M)[:, None]
         for t in range(1, seq):
             u = rng.random((M, b))
             # same overflow clamp as the per-client path above
             state = np.minimum(
-                (cums[midx, state] < u[..., None]).sum(axis=-1), V - 1)
+                (cums[midx, state] < u[..., None]).sum(axis=-1), S - 1)
             out[..., t] = state
-        return out
+        return self._emit(rng, out)
 
     def entropy_floor(self, client: int) -> float:
-        """Stationary conditional entropy of client's chain (nats/token)."""
+        """Stationary conditional entropy of client's token stream
+        (nats/token): the state chain's, plus the uniform emission's."""
         P = self.chains[client]
         # stationary distribution via power iteration
         pi = np.full(P.shape[0], 1.0 / P.shape[0])
         for _ in range(500):
             pi = pi @ P
         h = -np.sum(pi[:, None] * P * np.log(P + 1e-12))
+        if self.num_states < self.vocab_size:
+            h += np.sum(pi * np.log(np.diff(self._bounds)))
         return float(h)

@@ -1,5 +1,6 @@
 """Pallas TPU kernels for the compute hot-spots (validated in interpret mode
-on CPU; TPU v5e is the deployment target):
+on CPU, compiled for a described v5e chip in tests/test_tpu_compile.py, and
+checked against their references on the chip by chip_smoke.py):
 
   flash_attention/  blockwise fused attention (causal, sliding-window, GQA)
   flash_decode/     single-query attention over a padded, kv_valid-masked
@@ -9,4 +10,5 @@ on CPU; TPU v5e is the deployment target):
 
 Each has kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper) and
 ref.py (pure-jnp oracle used by tests and by the CPU/dry-run math path).
+platform.py picks compiled (TPU) or interpret (CPU) mode.
 """

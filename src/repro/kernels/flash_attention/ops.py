@@ -14,10 +14,7 @@ import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import mha_reference
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import interpret_default
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -28,7 +25,7 @@ def flash_attention(q, k, v, causal=True, window=0, block_q=128, block_k=128):
     vt = v.transpose(0, 2, 1, 3)
     out = flash_attention_fwd(
         qt, kt, vt, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=_interpret_default(),
+        block_k=block_k, interpret=interpret_default(),
     )
     return out.transpose(0, 2, 1, 3)
 

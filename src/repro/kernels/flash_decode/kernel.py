@@ -7,7 +7,8 @@ data, not per-shape structure. The kernel is a split-KV online-softmax
 reduction: the KV axis is the innermost *sequential* grid dimension, each
 split carries (m, l, acc) partials in VMEM scratch, and splits entirely
 past `kv_valid` (or entirely left of the sliding window) are skipped via
-@pl.when on the prefetched per-row scalars.
+@pl.when on the per-row scalars, which arrive through scalar prefetch
+(SMEM) rather than as (1, 1) VMEM blocks the TPU's tiling rule refuses.
 
 One numerical trap specific to decode: a split can be FULLY masked (e.g.
 the first split of a windowed row whose window starts in a later split).
@@ -31,12 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _decode_kernel(
-    kv_valid_ref, q_off_ref,  # [1,1] int32 per-row scalars
+    kv_valid_ref, q_off_ref,  # [B] int32 per-row scalars (SMEM)
     q_ref, k_ref, v_ref,  # [1,1,G,D], [1,1,Bk,D], [1,1,Bk,D]
     o_ref,  # [1,1,G,D]
     m_scr, l_scr, acc_scr,  # VMEM scratch: [G,1], [G,1], [G,D]
@@ -45,6 +43,7 @@ def _decode_kernel(
     block_k: int,
     window: int,
 ):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -54,8 +53,8 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_valid = kv_valid_ref[0, 0]
-    q_off = q_off_ref[0, 0]
+    kv_valid = kv_valid_ref[b]
+    q_off = q_off_ref[b]
     k_start = ik * block_k
     # split visibility: skip splits entirely past the live cache region or
     # entirely left of the sliding window
@@ -122,25 +121,31 @@ def flash_decode_fwd(
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=block_k, window=window)
-    return pl.pallas_call(
-        kernel,
+    # index maps take the two scalar-prefetch refs after the grid indices
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, ik, *_: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, ik, *_: (b, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, ik, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(kv_valid.reshape(B, 1), q_offset.reshape(B, 1), q, k, v)
+    )(kv_valid, q_offset, q, k, v)

@@ -13,10 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_decode.kernel import flash_decode_fwd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import interpret_default
 
 
 def flash_decode(
@@ -47,6 +44,6 @@ def flash_decode(
     vt = v.transpose(0, 2, 1, 3)
     out = flash_decode_fwd(
         qt, kt, vt, kv_valid, q_offset, window=window, block_k=block_k,
-        interpret=_interpret_default() if interpret is None else interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )
     return out.reshape(B, 1, Hq, D)

@@ -1,15 +1,10 @@
 """jit'd wrapper for the fused MTSL update kernel."""
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.mtsl_update.kernel import mtsl_update_fwd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import interpret_default
 
 
 def mtsl_update(p, g, eta):
     """p <- p - eta * g (eta scalar). Pallas-fused on TPU; interpret on CPU."""
-    return mtsl_update_fwd(p, g, eta, interpret=_interpret_default())
+    return mtsl_update_fwd(p, g, eta, interpret=interpret_default())

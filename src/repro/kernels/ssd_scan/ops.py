@@ -8,10 +8,7 @@ import jax
 
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 from repro.kernels.ssd_scan.ref import ssd_reference
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.platform import interpret_default
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -19,7 +16,7 @@ def ssd_scan(x, dt, A, Bm, Cm, chunk=128, initial_state=None):
     if initial_state is not None:
         # kernel assumes zero initial state; fold a nonzero one via the ref
         return ssd_reference(x, dt, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
-    return ssd_scan_fwd(x, dt, A, Bm, Cm, chunk=chunk, interpret=_interpret_default())
+    return ssd_scan_fwd(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret_default())
 
 
 def _fwd(x, dt, A, Bm, Cm, chunk, initial_state):

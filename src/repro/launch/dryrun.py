@@ -8,9 +8,12 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --all --json out.json
 """
 # The VERY FIRST lines, before ANY other import: jax locks the device count
-# on first init, and the production mesh needs 512 placeholder devices.
+# on first init, and the production mesh needs 512 placeholder host
+# devices. JAX_PLATFORMS=cpu keeps a dry run off any attached accelerator,
+# which belongs to the one process that drives it.
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")

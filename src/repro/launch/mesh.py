@@ -11,25 +11,22 @@ touch jax device state (the dry-run sets XLA_FLAGS before any jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-# TPU v5e hardware constants (roofline §g)
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+
+def make_mesh(shape, axes, *, devices=None):
+    """`jax.make_mesh` with every axis `Auto`. The round builders place
+    client leaves with `with_sharding_constraint`, which accepts only Auto
+    axes (jax.make_mesh defaults to Explicit ones)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(devices=None):
-    """Small host mesh for integration tests (8 fake CPU devices: 2x2x2)."""
-    n = len(jax.devices()) if devices is None else devices
-    if n >= 8:
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    return jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    return make_mesh(shape, axes)
 
 
 def num_clients_for(mesh) -> int:
@@ -91,4 +88,4 @@ def make_mesh_from_spec(spec):
             "available (force more host CPU devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             "jax initializes)")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -21,6 +21,7 @@ from repro.core.split import stack_towers
 from repro.models.registry import build_model
 from repro.serve.engine import ServeEngine
 from repro.train.checkpoint import load_checkpoint
+from repro.utils.jit_cache import enable_compilation_cache
 from repro.utils.sharding import strip
 
 
@@ -115,7 +116,9 @@ def run_bench(model, params, cfg, M: int, b: int, prompt_len: int,
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-130m")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced config (CPU tests); default is "
+                         "the registered config at its published widths")
     ap.add_argument("--batch-per-client", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -179,4 +182,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

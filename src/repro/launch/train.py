@@ -1,8 +1,8 @@
 """Training launcher.
 
-CPU-runnable end-to-end training: picks the smoke/paper-scale variant of
---arch and actually trains on synthetic heterogeneous data (this is what
-examples/train_lm.py drives).
+End-to-end training on synthetic heterogeneous data: --arch names a
+registered config, run at its published widths; --smoke swaps in the
+reduced variant that runs in seconds on the CPU.
 
 Massive-M scale-out (core/client_axis.py, README "Scaling"):
   * `--mesh data=N[,model=K[,pod=P]]` shards the client axis of every
@@ -40,7 +40,8 @@ training math is unchanged; history gains "sim_time", the simulated
 wall-clock (per-client compute + per-link transfer).
 
 Usage:
-    PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --steps 100
+    PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --smoke \
+        --steps 100
     PYTHONPATH=src python -m repro.launch.train --arch paper-mlp \
         --algorithm fedem --hp num_components=4
     PYTHONPATH=src python -m repro.launch.train --arch paper-mlp \
@@ -69,6 +70,7 @@ from repro.launch.mesh import make_mesh_from_spec, parse_mesh_spec
 from repro.models.registry import build_model
 from repro.optim import adamw, sgd
 from repro.train.loop import TrainConfig, train
+from repro.utils.jit_cache import enable_compilation_cache
 
 # scalar HParams fields settable via --hp key=value (registry-driven: any
 # new field with a bool/int/float default is exposed automatically)
@@ -276,14 +278,18 @@ def main(argv=None):
                          "numpy RNG pass across all clients (host cost per "
                          "client flat in M) instead of the per-client loop; "
                          "same distribution, different seeded stream")
-    ap.add_argument("--smoke", action="store_true", help="use reduced config")
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the reduced config (CPU tests); default is "
+                         "the registered config at its published widths")
+    ap.add_argument("--log-every", type=int, default=20,
+                    help="log (and record in history) every N rounds; the "
+                         "first and last round always log")
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    # full paper-scale configs run on CPU; assigned archs use smoke variants
-    cfg = get_config(args.arch,
-                     smoke=args.smoke or not args.arch.startswith("paper-"))
+    # the registered config at its published widths unless --smoke
+    cfg = get_config(args.arch, smoke=args.smoke)
     if args.num_clients is not None:
         cfg = cfg.with_updates(num_clients=args.num_clients)
     M = cfg.num_clients
@@ -389,6 +395,7 @@ def main(argv=None):
     clr = lr_policy.server_scaled(M, args.server_lr_scale)
     tcfg = TrainConfig(steps=args.steps, algorithm=args.algorithm,
                        lr=args.lr, local_steps=args.local_steps,
+                       log_every=args.log_every,
                        checkpoint_path=args.checkpoint,
                        checkpoint_every=100 if args.checkpoint else 0,
                        seed=args.seed,
@@ -416,4 +423,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

@@ -1,33 +1,31 @@
-"""Persistent jit-compilation cache switch, shared by the test suite
-(tests/conftest.py), the benchmark harness (benchmarks/common.py,
-benchmarks/run.py), and anything else that retraces the seven algorithms:
-compile each program once per cache directory, not once per process.
+"""Persistent jit-compilation cache switch, shared by the launchers,
+chip_smoke.py and the benchmark harness: compile each program once per
+cache directory, not once per process.
 
-CI restores the directory between runs (actions/cache keyed on the jax
-install) and points JAX_COMPILATION_CACHE_DIR at it.
+The directory is JAX_COMPILATION_CACHE_DIR when that is set (CI restores
+it between runs), and otherwise the fixed in-checkout
+`<repo>/.jax-compilation-cache` (listed in .gitignore). The path is part of
+the cache's key, so it is never derived from a temp dir, pid or time.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 
 import jax
 
+DEFAULT_CACHE_DIR = (pathlib.Path(__file__).resolve().parents[3]
+                     / ".jax-compilation-cache")
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Turn on JAX's persistent compilation cache.
 
-    Reads JAX_COMPILATION_CACHE_DIR when `path` is None; returns the
-    directory in use, or None when disabled/unsupported. Safe to call
-    repeatedly."""
-    path = path or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        return None
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every trace, however small/fast — wall time here is
-        # dominated by many short compiles, which the defaults would skip
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # older jax without these knobs
-        return None
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    Safe to call repeatedly."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    # cache every trace, however small/fast — wall time here is dominated
+    # by many short compiles, which the defaults would skip
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path

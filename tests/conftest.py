@@ -1,17 +1,20 @@
 """Shared test fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
 must see the single real CPU device (the 512-device emulation is exclusive
 to launch/dryrun.py, which tests spawn as a subprocess)."""
+import os
+
 import jax
 import numpy as np
 import pytest
 
 from repro.utils.jit_cache import enable_compilation_cache
 
-# Persistent jit-compile cache (CI sets JAX_COMPILATION_CACHE_DIR and
-# restores the directory between runs): the suite traces the same seven
-# algorithms over and over — compile each program once per cache, not once
-# per run. No-op when the env var is unset.
-enable_compilation_cache()
+# Persistent jit-compile cache, opt-in for the suite (CI sets
+# JAX_COMPILATION_CACHE_DIR and restores the directory between runs): the
+# suite traces the same seven algorithms over and over — compile each
+# program once per cache, not once per run.
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    enable_compilation_cache()
 
 
 @pytest.fixture(scope="session")

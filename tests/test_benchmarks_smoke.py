@@ -149,3 +149,22 @@ def test_serving_load_quick_end_to_end(tmp_path):
     assert d["claims"]["continuous_lower_p99_ttft"] is True
     assert cont["ttft_p99_s"] < seq["ttft_p99_s"]
     assert d["claims"]["greedy_parity_smoke"] is True
+
+
+@pytest.mark.parametrize("outcome,rc", [
+    ("pass", 0), ("fail_claim", 1), ("raise", 1)])
+def test_benchmark_runner_exit_code(monkeypatch, capsys, outcome, rc):
+    """benchmarks/run.py exits nonzero when a suite raises or a claim
+    FAILs, so a broken suite cannot pass as a clean run."""
+    from benchmarks import run as runner
+    from benchmarks import table2_accuracy
+
+    def suite(quick, json_path):
+        if outcome == "raise":
+            raise RuntimeError("suite broke")
+        return [("table2/claim", 0, "PASS" if outcome == "pass" else "FAIL")]
+
+    monkeypatch.setattr(table2_accuracy, "run", suite)
+    assert runner.main(["--only", "table2"]) == rc
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    assert last == f"claims_failed,{rc},{'OK' if rc == 0 else 'CHECK'}"

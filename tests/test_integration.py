@@ -84,8 +84,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import repro.launch.mesh as meshmod
 meshmod.make_production_mesh = lambda multi_pod=False: (
-    jax.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
-    else jax.make_mesh((2, 4), ("data", "model")))
+    meshmod.make_mesh((2, 2, 2), ("pod", "data", "model")) if multi_pod
+    else meshmod.make_mesh((2, 4), ("data", "model")))
 import repro.launch.dryrun as dr
 dr.make_production_mesh = meshmod.make_production_mesh
 import repro.configs.base as cb

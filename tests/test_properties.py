@@ -12,6 +12,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import comm_cost
 from repro.configs import get_config
 from repro.data.synthetic import heterogeneous_label_dist
+from repro.launch.mesh import make_mesh
 from repro.utils.sharding import logical_to_spec
 from repro.utils import tree as tu
 
@@ -26,7 +27,7 @@ _MESH = None
 def _mesh():
     global _MESH
     if _MESH is None:
-        _MESH = jax.make_mesh((1, 1), ("data", "model"))
+        _MESH = make_mesh((1, 1), ("data", "model"))
     return _MESH
 
 
@@ -46,7 +47,7 @@ def test_spec_is_always_valid(logical, dims):
     by its axis product and no mesh axis is used twice."""
     n = min(len(logical), len(dims))
     logical, dims = logical[:n], dims[:n]
-    mesh = jax.make_mesh((2, 4), ("data", "model")) if len(jax.devices()) >= 8 \
+    mesh = make_mesh((2, 4), ("data", "model")) if len(jax.devices()) >= 8 \
         else _mesh()
     spec = logical_to_spec(mesh, logical, dims)
     used = []

@@ -1,5 +1,7 @@
 """Convergence theory (paper §3) and synthetic-data behaviour."""
 import numpy as np
+import pytest
+
 from repro.core.theory import paper_fig2_setup
 from repro.data.lm import MultiTaskLMSource
 from repro.data.synthetic import MultiTaskImageSource
@@ -146,3 +148,53 @@ def test_lm_clamp_leaves_seeded_streams_unchanged(nprng):
     b = src.client_tokens(np.random.default_rng(9), 0, 4, 12)
     np.testing.assert_array_equal(a, b)
     assert 0 <= a.min() and a.max() < 16
+
+
+def test_lm_source_real_vocab_bounded_build():
+    """At mamba2-130m's V=50,280 the chains stay S x S (S = MAX_STATES),
+    so building M+1 of them takes megabytes, not the ~20 GB per dense
+    V x V chain."""
+    import tracemalloc
+
+    V, M = 50_280, 8
+    tracemalloc.start()
+    src = MultiTaskLMSource(vocab_size=V, num_clients=M, beta=1.0, seed=0)
+    _, peak = tracemalloc.get_traced_memory()
+    tracemalloc.stop()
+    S = src.num_states
+    assert S < V
+    assert all(p.shape == (S, S) for p in src.chains)
+    # M+1 float64 S x S matrices plus working copies
+    assert peak < 8 * (M + 1) * S * S * 8, peak
+    # the emission groups partition the vocabulary
+    assert src._bounds[0] == 0 and src._bounds[-1] == V
+    assert np.array_equal(np.sort(src._perm), np.arange(V))
+
+
+@pytest.mark.parametrize("vectorized", [False, True])
+def test_lm_source_real_vocab_draws_in_range(vectorized):
+    """Draws at V=50,280 are in [0, V), cover many more ids than there are
+    states, and each token's group names the state chain's next state."""
+    V = 50_280
+    src = MultiTaskLMSource(vocab_size=V, num_clients=4, beta=0.5, seed=1)
+    t = src.all_clients_batch(np.random.default_rng(0), 4, 512,
+                              vectorized=vectorized)
+    assert t.shape == (4, 4, 512) and t.dtype == np.int64
+    assert t.min() >= 0 and t.max() < V
+    assert len(np.unique(t)) > src.num_states
+    # token -> state is a function (the stream stays first-order Markov)
+    inv = np.empty(V, np.int64)
+    inv[src._perm] = np.arange(V)
+    states = np.searchsorted(src._bounds, inv[t], side="right") - 1
+    assert states.min() >= 0 and states.max() < src.num_states
+    h = src.entropy_floor(0)
+    assert np.log(V / src.num_states) < h < np.log(V)
+
+
+def test_lm_source_small_vocab_keeps_tokens_as_states():
+    """V <= MAX_STATES keeps the historical construction: states are the
+    tokens, and the chains are V x V."""
+    src = MultiTaskLMSource(vocab_size=32, num_clients=2, seed=0)
+    assert src.num_states == 32
+    assert src.chains[0].shape == (32, 32)
+    assert not hasattr(src, "_perm")
