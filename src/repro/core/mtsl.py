@@ -120,7 +120,8 @@ def make_loss_fn(model: Model, num_clients: int) -> Callable:
         accuracy-numerator contribution, and its aux term. Mirrors the
         dense body below with M -> c."""
         inputs = {k: v for k, v in batch_c.items() if k != "label"}
-        smashed = jax.vmap(model.tower_forward)(towers_c, inputs)
+        with jax.named_scope("mtsl.tower"):
+            smashed = jax.vmap(model.tower_forward)(towers_c, inputs)
         if part_c is not None:
             smashed = jax.tree.map(
                 lambda s: jnp.where(
@@ -128,7 +129,13 @@ def make_loss_fn(model: Model, num_clients: int) -> Callable:
                     s, jax.lax.stop_gradient(s)),
                 smashed)
         flat = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), smashed)
-        logits, aux = model.server_forward(server, flat)
+        with jax.named_scope("mtsl.server"):
+            logits, aux = model.server_forward(server, flat)
+        with jax.named_scope("mtsl.loss"):
+            return _chunk_loss(logits, aux, batch_c, sm_c, sd_c, c)
+
+    def _chunk_loss(logits, aux, batch_c, sm_c, sd_c, c):
+        """The chunk's per-task losses and accuracy numerator."""
         if is_classifier:
             labels = batch_c["label"].reshape(-1)
             logits32 = logits.astype(jnp.float32)
@@ -192,8 +199,9 @@ def make_loss_fn(model: Model, num_clients: int) -> Callable:
         (acc_num, aux), per_chunks = jax.lax.scan(body, (zero, zero), xs)
         per = per_chunks.reshape(M)
         per = client_axis.constrain_clients(per, shard)
-        wper = per if participation is None else per * participation
-        loss = jnp.sum(wper) + aux
+        with jax.named_scope("mtsl.loss"):
+            wper = per if participation is None else per * participation
+            loss = jnp.sum(wper) + aux
         if not is_classifier:
             return loss, {"loss": loss, "per_task": per, "aux": aux}
         width = jax.tree.leaves(batch)[0].shape[1]
@@ -213,7 +221,8 @@ def make_loss_fn(model: Model, num_clients: int) -> Callable:
             return _chunked_loss(params, batch, participation, sample_mask,
                                  sample_denom, chunk)
         inputs = {k: v for k, v in batch.items() if k != "label"}
-        smashed = jax.vmap(model.tower_forward)(params["towers"], inputs)
+        with jax.named_scope("mtsl.tower"):
+            smashed = jax.vmap(model.tower_forward)(params["towers"], inputs)
         if participation is not None:
             # sever non-participants' backward path entirely (per-task AND
             # aux losses); where() with an all-true mask is the identity
@@ -227,8 +236,15 @@ def make_loss_fn(model: Model, num_clients: int) -> Callable:
         flat = jax.tree.map(
             lambda x: x.reshape((-1,) + x.shape[2:]), smashed
         )
-        logits, aux = model.server_forward(params["server"], flat)
+        with jax.named_scope("mtsl.server"):
+            logits, aux = model.server_forward(params["server"], flat)
+        with jax.named_scope("mtsl.loss"):
+            return _dense_loss(logits, aux, batch, participation,
+                               sample_mask, sample_denom)
 
+    def _dense_loss(logits, aux, batch, participation, sample_mask,
+                    sample_denom):
+        """The dense path's loss and metrics from the server's logits."""
         if is_classifier:
             labels = batch["label"].reshape(-1)
             logits32 = logits.astype(jnp.float32)
@@ -407,6 +423,10 @@ def build_train_phases(
     def apply_step(state: TrainState, grads, metrics,
                    component_lr: Optional[ComponentLR] = None,
                    participation=None):
+        with jax.named_scope("mtsl.update"):
+            return _apply(state, grads, metrics, component_lr, participation)
+
+    def _apply(state, grads, metrics, component_lr, participation):
         grads = sync(grads)
         updates, opt_state = opt.update(
             grads, state.opt_state, state.params, state.step,

@@ -54,12 +54,20 @@ def client_batches(
             batch = jax.tree.map(lambda a: jax.device_put(a, sharding), batch)
         return batch
 
+    def _draw(i):
+        # the span closes before the yield: it excludes the time the
+        # consumer holds the generator suspended
+        return jax.profiler.TraceAnnotation("repro.draw",
+                                            round=start_round + i + 1)
+
     if hasattr(source, "round_batch"):  # ShardableDataset: cached reads
         kwargs = {"seq_len": seq_len} if source.kind == "lm" else {}
         i = 0
         while steps is None or i < steps:
-            yield _emit(source.round_batch(seed, start_round + i,
-                                           batch_per_client, **kwargs))
+            with _draw(i):
+                batch = source.round_batch(seed, start_round + i,
+                                           batch_per_client, **kwargs)
+            yield _emit(batch)
             i += 1
         return
     if start_round:
@@ -71,13 +79,15 @@ def client_batches(
     i = 0
     is_lm = hasattr(source, "chains")
     while steps is None or i < steps:
-        if is_lm:
-            toks = source.all_clients_batch(rng, batch_per_client, seq_len,
-                                            vectorized=vectorized)
-            batch = {"tokens": np.asarray(toks, np.int32)}
-        else:
-            x, y = source.all_tasks_batch(rng, batch_per_client,
-                                          vectorized=vectorized)
-            batch = {"image": np.asarray(x), "label": np.asarray(y, np.int32)}
+        with _draw(i):
+            if is_lm:
+                toks = source.all_clients_batch(rng, batch_per_client,
+                                                seq_len, vectorized=vectorized)
+                batch = {"tokens": np.asarray(toks, np.int32)}
+            else:
+                x, y = source.all_tasks_batch(rng, batch_per_client,
+                                              vectorized=vectorized)
+                batch = {"image": np.asarray(x),
+                         "label": np.asarray(y, np.int32)}
         yield _emit(batch)
         i += 1

@@ -91,12 +91,14 @@ def mamba_forward(p, x, cfg: ModelConfig, *, return_state: bool = False,
         dt_ = jnp.pad(dt_, ((0, 0), (0, padl), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, padl), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, padl), (0, 0)))
-    if cfg.use_flash_kernel:
-        from repro.kernels.ssd_scan.ops import ssd_scan
+    # one scope whatever implements the scan, so its device time reads alike
+    with jax.named_scope("mamba.ssd"):
+        if cfg.use_flash_kernel:
+            from repro.kernels.ssd_scan.ops import ssd_scan
 
-        y, state = ssd_scan(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
-    else:
-        y, state = ssd_reference(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
+            y, state = ssd_scan(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
+        else:
+            y, state = ssd_reference(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
     y = y[:, :L]
     y = y + xin.reshape(B_, L, H, P) * p["D"][None, None, :, None].astype(cdt)
     y = y.reshape(B_, L, d_in)

@@ -312,15 +312,15 @@ def train(
     # shards (per-device slices of the leading axis) instead of device 0
     stage_sharding = (client_sharding(tcfg.mesh)
                       if tcfg.mesh is not None else None)
-    for i, (batch, sched) in enumerate(
-            pipeline_rounds(batches, sched_iter, depth=tcfg.prefetch,
-                            num_rounds=remaining, device=stage_sharding)):
-        r = start_round + i + 1  # absolute 1-based round index
+    pairs = pipeline_rounds(batches, sched_iter, depth=tcfg.prefetch,
+                            num_rounds=remaining, device=stage_sharding)
+    for r, (batch, sched) in _traced_rounds(pairs, start_round, remaining):
         # read the batch's static width BEFORE dispatch: the sharded round
         # program donates the staged batch buffers on non-CPU backends
         b = (jax.tree.leaves(batch)[0].shape[1] // spr
              if round_sim_s is not None else None)
-        state, metrics = round_fn(state, batch, sched)
+        with jax.profiler.TraceAnnotation("repro.dispatch", round=r):
+            state, metrics = round_fn(state, batch, sched)
         rounds_done = r
         if round_sim_s is not None:
             sim_time += round_sim_s(r, b, sched)
@@ -330,7 +330,7 @@ def train(
         # resumed run must not record rounds an uninterrupted one would
         # skip (resume == uninterrupted, entry for entry)
         do_log = ((tcfg.log_every and r % tcfg.log_every == 0)
-                  or (i == 0 and start_round == 0) or r == rounds)
+                  or r == 1 or r == rounds)
         # eval runs on its OWN cadence — never gated behind the log cadence —
         # and its history entry is recorded unconditionally. The run's LAST
         # round always evals when eval is configured (matching _train_async
@@ -360,6 +360,7 @@ def train(
             save_algorithm_state(tcfg.checkpoint_path, alg, state,
                                  extra=extra)
             ckpt_round = r
+    pairs.close()
     ring.flush()
     if tcfg.checkpoint_path and rounds_done > ckpt_round:
         # always leave a final checkpoint behind (unless the last round's
@@ -369,6 +370,24 @@ def train(
             extra["sim_time"] = sim_time
         save_algorithm_state(tcfg.checkpoint_path, alg, state, extra=extra)
     return state, history
+
+
+def _traced_rounds(pairs, start_round: int, remaining: int):
+    """Yield `(r, (batch, schedule))` for the absolute 1-based rounds after
+    `start_round`, under profiler spans, which cost microseconds when no
+    trace runs. Round r's `repro.round` span stays open while the caller's
+    loop body runs (this generator is suspended inside it); within it,
+    `repro.input_wait` covers the take of the staged pair, with `queued`
+    the pairs the producer thread had ready."""
+    for r in range(start_round + 1, start_round + remaining + 1):
+        with jax.profiler.StepTraceAnnotation("repro.round", step_num=r,
+                                              round=r):
+            with jax.profiler.TraceAnnotation(
+                    "repro.input_wait", round=r, queued=pairs.queued()):
+                pair = next(pairs, None)
+            if pair is None:
+                return
+            yield r, pair
 
 
 def _train_async(model, tcfg, num_clients, alg, hp, scfg, cap, spr, rounds,

@@ -94,6 +94,10 @@ class BackgroundIterator:
                 continue
         return False
 
+    def queued(self) -> int:
+        """Items the producer has ready that the consumer has not taken."""
+        return self._q.qsize()
+
     def __iter__(self) -> "BackgroundIterator":
         return self
 
@@ -143,7 +147,7 @@ def pipeline_rounds(
     depth: int = 2,
     num_rounds: Optional[int] = None,
     device=None,
-) -> Iterator[tuple]:
+) -> "RoundStream":
     """Yield `(batch, schedule)` pairs with host work running ahead.
 
     depth=0: a plain synchronous `zip` (staged inline) — the opt-out path.
@@ -158,11 +162,40 @@ def pipeline_rounds(
     pairs: Iterable = zip(batches, schedules)
     if num_rounds is not None:
         pairs = itertools.islice(pairs, num_rounds)
+    return RoundStream(pairs, depth, device)
+
+
+class RoundStream:
+    """The iterator `pipeline_rounds` returns. `queued()` is the number of
+    pairs its producer thread has ready (0 when synchronous); `close()`
+    stops the thread."""
+
+    def __init__(self, pairs: Iterable, depth: int, device=None):
+        # the generator fills in its BackgroundIterator when it starts; a
+        # box rather than self keeps the generator out of a cycle with us
+        self._bg: list = [None]
+        self._gen = _staged_pairs(pairs, depth, device, self._bg)
+
+    def __iter__(self) -> "RoundStream":
+        return self
+
+    def __next__(self):
+        return next(self._gen)
+
+    def queued(self) -> int:
+        bg = self._bg[0]
+        return 0 if bg is None else bg.queued()
+
+    def close(self) -> None:
+        self._gen.close()
+
+
+def _staged_pairs(pairs: Iterable, depth: int, device, box: list):
     if depth <= 0:
         for batch, sched in pairs:
             yield _stage(batch, device), sched
         return
-    bg = BackgroundIterator(pairs, depth=depth)
+    bg = box[0] = BackgroundIterator(pairs, depth=depth)
     try:
         staged = None
         for pair in bg:
