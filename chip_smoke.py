@@ -16,8 +16,9 @@ a TPU, and any failed check or exception fails the run. Phases:
   serve    a full-width ContinuousEngine for 8 clients answers requests of
            mixed prompt lengths. Checks every request gets new_tokens
            tokens in [0, V) and that decode compiled once.
-  kernels  ssd_scan, flash_decode and flash_attention compiled for the chip
-           at mamba2-130m / GQA widths, each against its float32 reference.
+  kernels  ssd_scan (forward and backward), flash_decode and
+           flash_attention compiled for the chip at mamba2-130m / GQA
+           widths, each against its float32 reference.
   sharded  (--chips 4 only) one MTSL round sharded data=4 through
            shard_round_fn + place_algorithm_state against the dense
            jit_round_fn round on one device of the same host, both with
@@ -242,7 +243,7 @@ def phase_kernels(sz: Sizes, seed: int):
     from repro.kernels.flash_attention.ref import mha_reference
     from repro.kernels.flash_decode.kernel import flash_decode_fwd
     from repro.kernels.platform import interpret_default
-    from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
+    from repro.kernels.ssd_scan.ops import ssd_scan
     from repro.kernels.ssd_scan.ref import ssd_reference
 
     interpret = interpret_default()
@@ -263,14 +264,24 @@ def phase_kernels(sz: Sizes, seed: int):
     x, Bm, Cm = normal((B, L, H, P)), normal((B, L, N)), normal((B, L, N))
     dt = jnp.asarray(rng.uniform(0.01, 0.2, size=(B, L, H)), f32)
     A = -jnp.asarray(rng.uniform(1.0, 16.0, size=(H,)), f32)
-    y, st = jax.jit(lambda *a: ssd_scan_fwd(
-        *a, chunk=chunk, interpret=interpret))(x, dt, A, Bm, Cm)
-    yr, sr = reference(lambda *a: ssd_reference(*a, chunk=chunk),
-                       x, dt, A, Bm, Cm)
-    e_y, e_s = _rel_err(y, yr), _rel_err(st, sr)
-    check(e_y <= KERNEL_TOL and e_s <= KERNEL_TOL,
+    gy = normal((B, L, H, P))
+
+    def with_vjp(fn):
+        def run(*a):
+            (y, st), vjp = jax.vjp(fn, *a)
+            return y, st, vjp((gy.astype(y.dtype), jnp.zeros_like(st)))
+        return run
+
+    y, st, g = jax.jit(with_vjp(lambda *a: ssd_scan(*a, chunk)))(
+        x, dt, A, Bm, Cm)
+    yr, sr, gr = reference(with_vjp(lambda *a: ssd_reference(
+        *a, chunk=chunk)), x, dt, A, Bm, Cm)
+    errs = [_rel_err(a, b) for a, b in zip((y, st) + tuple(g),
+                                            (yr, sr) + tuple(gr))]
+    check(max(errs) <= KERNEL_TOL,
           f"ssd_scan B={B} L={L} H={H} P={P} N={N} chunk={chunk}: "
-          f"y {e_y:.2e}, final state {e_s:.2e}")
+          + ", ".join(f"{n} {e:.2e}" for n, e in zip(
+              ["y", "final state", "dx", "ddt", "dA", "dB", "dC"], errs)))
 
     B, cap, Hq, Hkv, D, bk = sz.decode
     q, k, v = normal((B, Hkv, Hq // Hkv, D)), normal((B, Hkv, cap, D)), \

@@ -110,7 +110,9 @@ class ModelConfig:
     fsdp: bool = False  # shard server params over the data axis too
     seq_shard: bool = False  # shard long activations over model axis
     microbatches: int = 1  # grad-accumulation steps inside train_step
-    use_flash_kernel: bool = False  # Pallas flash-attention (TPU target)
+    # Pallas flash attention only (TPU target); the SSD takes its fused
+    # kernels wherever they apply (kernels/ssd_scan/ops.py use_kernel)
+    use_flash_kernel: bool = False
     attn_impl: str = "ref"  # "ref" (full scores) | "chunked" (online softmax)
     attn_chunk: int = 1024  # KV chunk for attn_impl="chunked"
     decode_long_window: int = 0  # >0: SWA ring-buffer KV for long decode

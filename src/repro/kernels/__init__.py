@@ -5,7 +5,8 @@ checked against their references on the chip by chip_smoke.py):
   flash_attention/  blockwise fused attention (causal, sliding-window, GQA)
   flash_decode/     single-query attention over a padded, kv_valid-masked
                     KV cache (split-KV online softmax — the serving hot path)
-  ssd_scan/         Mamba2 SSD chunked scan with VMEM-carried state
+  ssd_scan/         Mamba2 SSD chunked scan, fused forward and backward
+                    with VMEM-carried state (the TPU training path)
   mtsl_update/      fused per-component-LR update (the paper's eta * g step)
 
 Each has kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper) and

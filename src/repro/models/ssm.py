@@ -2,8 +2,9 @@
 gated RMSNorm + output projection. Decode keeps (conv_state, ssm_state) and
 is O(1) per token — this is what makes the ssm/hybrid archs long_500k-able.
 
-Train/prefill math goes through kernels/ssd_scan (ref oracle by default,
-Pallas kernel when cfg.use_flash_kernel on the TPU target).
+Train/prefill math goes through kernels/ssd_scan: the fused Pallas
+kernels (forward and backward) on the TPU where ops.use_kernel allows,
+else the chunked reference, which is also the CPU path.
 """
 from __future__ import annotations
 
@@ -11,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.ssd_scan.ops import ssd_scan, use_kernel
 from repro.kernels.ssd_scan.ref import ssd_reference, ssd_decode_step
 from repro.models.layers import rmsnorm_params, rmsnorm
 from repro.nn import param
@@ -91,12 +93,16 @@ def mamba_forward(p, x, cfg: ModelConfig, *, return_state: bool = False,
         dt_ = jnp.pad(dt_, ((0, 0), (0, padl), (0, 0)))
         Bm = jnp.pad(Bm, ((0, 0), (0, padl), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, padl), (0, 0)))
+    # imported here: repro.core imports the models
+    from repro.core.client_axis import current_sharding
+
+    fused = use_kernel(jax.default_backend(), xh.shape, N, chunk,
+                       xh.dtype.itemsize, has_state=initial_state is not None,
+                       sharded=current_sharding() is not None)
     # one scope whatever implements the scan, so its device time reads alike
     with jax.named_scope("mamba.ssd"):
-        if cfg.use_flash_kernel:
-            from repro.kernels.ssd_scan.ops import ssd_scan
-
-            y, state = ssd_scan(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
+        if fused:
+            y, state = ssd_scan(xh, dt_, A, Bm, Cm, chunk)
         else:
             y, state = ssd_reference(xh, dt_, A, Bm, Cm, chunk=chunk, initial_state=initial_state)
     y = y[:, :L]
@@ -196,14 +202,8 @@ def mamba_extend(p, x_c, cache, n_valid, cfg: ModelConfig):
         dt_c = jnp.pad(dt_c, ((0, 0), (0, padl), (0, 0)))
         Bm_c = jnp.pad(Bm_c, ((0, 0), (0, padl), (0, 0)))
         Cm_c = jnp.pad(Cm_c, ((0, 0), (0, padl), (0, 0)))
-    if cfg.use_flash_kernel:
-        from repro.kernels.ssd_scan.ops import ssd_scan
-
-        y, state = ssd_scan(xh, dt_c, A, Bm_c, Cm_c, chunk=chunk,
-                            initial_state=cache["state"])
-    else:
-        y, state = ssd_reference(xh, dt_c, A, Bm_c, Cm_c, chunk=chunk,
-                                 initial_state=cache["state"])
+    y, state = ssd_reference(xh, dt_c, A, Bm_c, Cm_c, chunk=chunk,
+                             initial_state=cache["state"])
     y = y[:, :C]
     y = y + xin_c.reshape(B_, C, H, P) * p["D"][None, None, :, None].astype(cdt)
     y = y.reshape(B_, C, d_in)

@@ -242,6 +242,75 @@ def test_ssd_scan_matches_reference(case):
     np.testing.assert_allclose(np.asarray(st), np.asarray(sr), atol=1e-4)
 
 
+SSD_VJP_CASES = [
+    # (B, L, H, P, N, chunk, dtype, vmapped): one chunk and several, one
+    # lane slab and three (H·P = 384, one iteration of three slabs), N != P
+    (2, 64, 3, 8, 16, 16, jnp.float32, False),
+    (1, 32, 2, 16, 8, 32, jnp.float32, False),
+    (1, 256, 2, 64, 32, 128, jnp.float32, False),
+    (1, 64, 6, 64, 32, 32, jnp.float32, False),
+    (1, 128, 4, 32, 16, 128, jnp.bfloat16, False),
+    (2, 64, 2, 8, 8, 16, jnp.bfloat16, True),
+]
+
+
+@pytest.mark.parametrize("case", SSD_VJP_CASES)
+def test_ssd_scan_vjp_matches_reference(case):
+    """The fused forward's outputs and the fused backward's cotangents of
+    x, dt, A, B and C against jax.vjp of the chunked reference, with a
+    nonzero cotangent on the final state too; `vmapped` maps a client axis
+    over the call, as the round's towers do."""
+    B, L, H, P, N, chunk, dtype, vmapped = case
+    rng = np.random.default_rng(4)
+    lead = (2, B) if vmapped else (B,)
+    x = jnp.asarray(rng.normal(size=lead + (L, H, P)), dtype)
+    dt = jnp.asarray(rng.uniform(0.01, 0.2, size=lead + (L, H)), jnp.float32)
+    A = -jnp.asarray(rng.uniform(0.5, 2.0, size=(H,)), jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=lead + (L, N)), dtype)
+    Cm = jnp.asarray(rng.normal(size=lead + (L, N)), dtype)
+    gy = jnp.asarray(rng.normal(size=lead + (L, H, P)), dtype)
+    gs = jnp.asarray(rng.normal(size=lead + (H, P, N)), jnp.float32)
+
+    def run(fn):
+        if vmapped:
+            fn = jax.vmap(fn, in_axes=(0, 0, None, 0, 0))
+        out, vjp = jax.vjp(fn, x, dt, A, Bm, Cm)
+        return out, vjp((gy, gs))
+
+    (y, st), grads = run(lambda *a: ssd_scan(*a, chunk))
+    (yr, sr), grads_r = run(lambda *a: ssd_reference(*a, chunk=chunk))
+    # max abs error over the reference's largest entry: bf16 rounds the
+    # activations and the kernels' matmul operands (2**-8 relative)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    for name, a, b in zip(["y", "state", "x", "dt", "A", "B", "C"],
+                          (y, st) + tuple(grads), (yr, sr) + tuple(grads_r)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert a.shape == b.shape, name
+        err = np.max(np.abs(a - b)) / np.max(np.abs(b))
+        assert err < tol, (name, err)
+
+
+@pytest.mark.parametrize("backend, shape, chunk, has_state, sharded, want", [
+    ("tpu", (4, 512, 24, 64), 128, False, False, True),   # mamba2-130m
+    ("tpu", (2, 1024, 112, 64), 256, False, False, True),  # zamba2 chunk
+    ("cpu", (4, 512, 24, 64), 128, False, False, False),
+    ("gpu", (4, 512, 24, 64), 128, False, False, False),
+    ("tpu", (4, 512, 24, 64), 128, True, False, False),    # carried state
+    ("tpu", (4, 512, 24, 64), 128, False, True, False),    # client sharding
+    ("tpu", (4, 520, 24, 64), 128, False, False, False),   # partial chunk
+    ("tpu", (4, 512, 24, 64), 8, False, False, False),     # 8-row chunk
+    ("tpu", (4, 512, 6, 48), 128, False, False, False),    # heads off slabs
+])
+def test_ssd_kernel_dispatch_rule(backend, shape, chunk, has_state, sharded,
+                                  want):
+    """models/ssm.py takes the fused kernels only on the TPU, from a zero
+    state, without client sharding, where the blocks tile the shapes."""
+    from repro.kernels.ssd_scan.ops import use_kernel
+
+    assert use_kernel(backend, shape, 128, chunk, 2, has_state=has_state,
+                      sharded=sharded) is want
+
+
 def test_ssd_decode_chain_matches_scan():
     rng = np.random.default_rng(3)
     B, L, H, P, N = 2, 16, 2, 4, 8

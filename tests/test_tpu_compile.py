@@ -9,6 +9,7 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_decode.kernel import flash_decode_fwd
-from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 
 
 @pytest.fixture(scope="module")
@@ -48,12 +48,50 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
-def test_ssd_scan_compiles_at_mamba2_130m_widths(one_chip):
-    """mamba2-130m: 24 SSD heads of width 64, state 128, chunk 128."""
+def test_ssd_scan_compiles_at_mamba2_130m_widths(one_chip, monkeypatch):
+    """mamba2-130m: 24 SSD heads of width 64, state 128, chunk 128. The
+    gradient of a loss through ops.ssd_scan compiles to the fused forward
+    and the fused backward kernel, each named under the caller's
+    `mamba.ssd` scope (which the benchmark's SSD metrics read)."""
+    from repro.kernels.ssd_scan import ops
+
+    # the described chip is not the backend JAX sees: compile, not interpret
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
     B, L, H, P, N = 4, 512, 24, 64, 128
     bf16, f32 = jnp.bfloat16, jnp.float32
-    _compile(lambda x, dt, a, b, c: ssd_scan_fwd(x, dt, a, b, c, chunk=128),
-             one_chip, ((B, L, H, P), bf16), ((B, L, H), f32), ((H,), f32),
+
+    def loss(x, dt, a, b, c):
+        with jax.named_scope("mamba.ssd"):
+            y, st = ops.ssd_scan(x, dt, a, b, c, 128)
+        return jnp.sum(y.astype(f32)) + jnp.sum(st)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+                        ((B, L, H, P), bf16), ((B, L, H), f32), ((H,), f32),
+                        ((B, L, N), bf16), ((B, L, N), bf16))
+    calls = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%([\w-]+)", line).group(1)
+            calls[name] = re.search(r'op_name="([^"]*)"', line).group(1)
+    assert sorted(calls) == ["ssd_bwd", "ssd_fwd"], calls
+    assert all("mamba.ssd" in op_name for op_name in calls.values()), calls
+
+
+def test_ssd_scan_compiles_at_zamba2_widths_chunk_256(one_chip, monkeypatch):
+    """zamba2-7b's Mamba2 blocks (112 heads of 64, state 64) in 256-token
+    chunks, Mamba2's default chunk: the fused kernels' blocks still fit."""
+    from repro.kernels.ssd_scan import ops
+
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    B, L, H, P, N = 2, 1024, 112, 64, 64
+    bf16, f32 = jnp.bfloat16, jnp.float32
+
+    def loss(x, dt, a, b, c):
+        y, st = ops.ssd_scan(x, dt, a, b, c, 256)
+        return jnp.sum(y.astype(f32)) + jnp.sum(st)
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+             ((B, L, H, P), bf16), ((B, L, H), f32), ((H,), f32),
              ((B, L, N), bf16), ((B, L, N), bf16))
 
 
