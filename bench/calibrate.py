@@ -42,17 +42,23 @@ def program(ns, cell):
 
 
 def fault(ns, cell):
+    import jax
+
     import faults
     import train_cell
 
+    axes = train_cell.layout(cell)[2]
+    mesh = train_cell.cell_mesh(cell, jax.devices()[:cell.chips])
     for seed in ns.seeds:
         inputs = []
         it = iter(train_cell.source(cell, seed))
         for _ in range(cell.traffic["check_rounds"]):
             inputs.append(next(it))
-        ref = train_cell.reference_checks(cell, seed, inputs)
+        ref = train_cell.reference_checks(cell, seed, inputs, axes,
+                                          mesh=mesh)
         bad = train_cell.reference_checks(cell, seed,
-                                          faults.half_batch_inputs(inputs))
+                                          faults.half_batch_inputs(inputs),
+                                          axes, mesh=mesh)
         print(json.dumps({"seed": seed, "fault": "half_batch",
                           "numbers": check.train_numbers(bad, ref)[0]}),
               flush=True)

@@ -4,11 +4,15 @@ compile counter, the profiler window, the metric readers and the result line.
 A cell `<config>.<traffic>` of BENCHMARK.json names a configuration file
 `configs/<config>.json`, a traffic file `traffic/<traffic>.json` (whose
 `kind` names the runner module `<kind>_cell.py`), and a limits file
-`limits/<cell>.json`. Each metric of BENCHMARK.json is read by
-`metrics/<metric>.py`, whose `read(run)` returns a number or None.
+`limits/<cell>.json`. The configuration's `family` names its plug-in
+`families/<family>.py` (see `train_cell.py` for what it gives), looked up
+beside the cell's files first and then among the benchmark's own. Each
+metric of BENCHMARK.json is read by `metrics/<metric>.py`, whose
+`read(run)` returns a number or None.
 """
 from __future__ import annotations
 
+import functools
 import glob
 import importlib.util
 import json
@@ -37,7 +41,7 @@ class Cell:
             raise SystemExit(f"no workload {name!r} in BENCHMARK.json; have "
                              f"{sorted(cells)}")
         w = cells[name]
-        self.name, self.chips = name, w["chips"]
+        self.name, self.chips, self.files = name, w["chips"], files
         self.config = load_json(files / "configs" / f"{w['config']}.json")
         self.traffic = load_json(files / "traffic" / f"{w['traffic']}.json")
         self.limits = load_json(files / "limits" / f"{name}.json")["limits"]
@@ -47,6 +51,21 @@ class Cell:
 
         self.end_to_end = [m for m in spec["end_to_end"] if mine(m)]
         self.per_layer = [m for m in spec["per_layer"] if mine(m)]
+
+    @functools.cached_property
+    def family(self):
+        """The configuration's family plug-in module."""
+        return family(self.config["family"], self.files)
+
+
+def family(name, files=BENCH):
+    """The plug-in `families/<name>.py`, from `files` or else the
+    benchmark's own."""
+    for d in (files, BENCH):
+        path = d / "families" / f"{name}.py"
+        if path.exists():
+            return _load(path, "bench_family_" + name)
+    raise SystemExit(f"bench: no plug-in families/{name}.py")
 
 
 def runner(cell):
@@ -127,13 +146,19 @@ class Profile:
         return trace_mod.reduce_file(files[-1], chips)
 
 
-def reader(metric):
-    path = BENCH / "metrics" / f"{metric}.py"
+def _load(path, name):
+    """The module in file `path`, run under `name` (dots and dashes made
+    underscores); it is not entered in sys.modules."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric):
+    return _load(BENCH / "metrics" / f"{metric}.py",
+                 "bench_metric_" + metric).read
 
 
 def read_metrics(metrics, run):

@@ -19,7 +19,6 @@ import jax.numpy as jnp  # noqa: E402
 
 import harness  # noqa: E402
 import train_cell  # noqa: E402
-import weights  # noqa: E402
 
 SPEC = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
 TRAIN = [w["name"] for w in SPEC["workloads"] if w["chips"] == 1
@@ -59,9 +58,9 @@ def _bytes(compiled):
 
 
 def _weights(cell):
-    M = cell.traffic["clients"]
-    return jax.eval_shape(lambda: weights.MAKERS[cell.config["family"]](
-        jax.random.PRNGKey(0), train_cell.ref_cfg(cell.config), M))
+    fam, M = cell.family, cell.traffic["clients"]
+    return jax.eval_shape(lambda: fam.make_params(
+        jax.random.PRNGKey(0), fam.ref_cfg(cell.config), M))
 
 
 def _batch(cell):
@@ -81,11 +80,10 @@ def test_round_program_fits_one_chip(name, one_chip):
     from repro.core.algorithms import HParams, get_algorithm
     from repro.core.mtsl import TrainState
     from repro.core.schedule import full_schedule
-    from repro.models.registry import build_model
 
     cell = harness.Cell(name, SPEC)
     t, M = cell.traffic, cell.traffic["clients"]
-    model = build_model(train_cell.program_config(cell.config, M))
+    model = train_cell.layout(cell)[0]
     opt = train_cell.optimizer(t["optimizer"])
     hp = HParams(optimizer=opt, component_lr=lr_policy.server_scaled(
         M, t["server_lr_scale"]))
@@ -107,7 +105,7 @@ def test_reference_step_fits_one_chip(name, one_chip):
     from reference import mtsl as ref_mtsl
 
     cell = harness.Cell(name, SPEC)
-    step, opt = train_cell.reference_step(cell)
+    step, opt = train_cell.reference_step(cell, train_cell.layout(cell)[2])
     params = _weights(cell)
     opt_state = jax.eval_shape(lambda p: ref_mtsl.init_opt(opt, p), params)
     compiled = step.lower(_on(params, one_chip), _on(opt_state, one_chip),
@@ -126,7 +124,6 @@ def test_sharded_round_for_four_chips(topo):
     from repro.core.mtsl import TrainState
     from repro.core.schedule import full_schedule
     from repro.launch.mesh import make_mesh
-    from repro.models.registry import build_model
     from repro.utils.sharding import client_sharding, replicated_sharding
 
     cell = harness.Cell("mamba2-130m.train.m8-s512", SPEC)
@@ -134,7 +131,7 @@ def test_sharded_round_for_four_chips(topo):
     cell.traffic = t
     mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
     alg = get_algorithm("mtsl")
-    model = build_model(train_cell.program_config(cell.config, M))
+    model = train_cell.layout(cell)[0]
     opt = train_cell.optimizer(t["optimizer"])
     hp = HParams(optimizer=opt, component_lr=lr_policy.server_scaled(
         M, 1.0 / M))
@@ -149,3 +146,31 @@ def test_sharded_round_for_four_chips(topo):
     compiled = fn.lower(state, _on(_batch(cell), cs), sched).compile()
     assert _bytes(compiled) < CHIP_BYTES
     assert compiled.as_text().count("all-reduce(") >= 1
+
+
+def test_sharded_reference_for_four_chips(topo):
+    """The deferred four-chip cell's reference: mamba2-130m at M=16 needs
+    more than one chip (16.8 GB), so with the traffic's mesh it runs split
+    by client over the 2x2 host (train_cell.reference_checks); it fits
+    each chip."""
+    from reference import mtsl as ref_mtsl
+    from repro.launch.mesh import make_mesh
+
+    cell = harness.Cell("mamba2-130m.train.m8-s512", SPEC)
+    cell.traffic = dict(cell.traffic, clients=16)
+    mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
+    where = train_cell.placement(mesh)
+    split, whole = where["towers"], where["server"]
+    step, opt = train_cell.reference_step(cell, train_cell.layout(cell)[2])
+    params = _weights(cell)
+    params = {"towers": _on(params["towers"], split),
+              "server": _on(params["server"], whole)}
+    opt_state = jax.eval_shape(lambda p: ref_mtsl.init_opt(opt, p), params)
+    opt_state = jax.tree.map(
+        lambda x, p: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=p.sharding),
+        opt_state, {"mu": params, "nu": params})
+    compiled = step.lower(params, opt_state, _on(_batch(cell), split),
+                          jax.ShapeDtypeStruct((), jnp.float32,
+                                               sharding=whole)).compile()
+    assert _bytes(compiled) < CHIP_BYTES

@@ -9,9 +9,10 @@ import sys
 import pytest
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(BENCH))
+sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
 
 import flops  # noqa: E402
+import harness  # noqa: E402
 
 
 def _load(kind, name):
@@ -26,9 +27,10 @@ def test_mamba2_130m_by_hand():
     assert layer == 8_505_344
     per_token = 24 * layer + 77_230_080
     cfg = _load("configs", "mamba2-130m")
-    assert flops.mamba2_forward_per_token(cfg) == per_token == 281_358_336
+    fam = harness.family(cfg["family"])
+    assert fam.forward_per_token(cfg) == per_token == 281_358_336
     traffic = _load("traffic", "train.m8-s512")
-    assert flops.train_round(cfg, traffic) == 3 * 16_384 * per_token
+    assert fam.train_round_flops(cfg, traffic) == 3 * 16_384 * per_token
 
 
 def test_resnet20_by_hand():
@@ -44,9 +46,10 @@ def test_resnet20_by_hand():
     per_image = stem + stage0 + stage1 + stage2 + head
     assert per_image == 81_626_368
     cfg = _load("configs", "cifar-resnet20")
-    assert flops.resnet_forward_per_image(cfg) == per_image
+    fam = harness.family(cfg["family"])
+    assert fam.forward_per_image(cfg) == per_image
     traffic = _load("traffic", "train.m10")
-    assert flops.train_round(cfg, traffic) == 3 * 160 * per_image
+    assert fam.train_round_flops(cfg, traffic) == 3 * 160 * per_image
 
 
 def test_peaks_table():

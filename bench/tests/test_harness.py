@@ -34,6 +34,9 @@ def test_every_name_finds_its_files():
         assert [m["name"] for m in cell.end_to_end][0] == "setup_s"
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         assert w["name"] == f"{w['config']}.{w['traffic']}"
+        for f in ("ref_cfg", "program_want", "make_params",
+                  "train_round_flops", "loss_and_grads"):
+            assert callable(getattr(cell.family, f)), (w["config"], f)
 
 
 def test_bounds_and_lengths():

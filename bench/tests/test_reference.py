@@ -14,11 +14,11 @@ sys.path[:0] = [str(BENCH), str(BENCH.parent / "src")]
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+import harness  # noqa: E402
 import train_cell  # noqa: E402
 import weights  # noqa: E402
 from reference import mamba2 as ref_mamba2  # noqa: E402
 from reference import mtsl as ref_mtsl  # noqa: E402
-from reference import resnet as ref_resnet  # noqa: E402
 
 FIX = BENCH / "tests" / "fixtures"
 
@@ -51,7 +51,8 @@ def _program_round(config, traffic, params, batch):
     from repro.models.registry import build_model
 
     M = traffic["clients"]
-    model = build_model(train_cell.program_config(config, M))
+    model = build_model(train_cell.program_config(
+        config, M, harness.family(config["family"])))
     opt = train_cell.optimizer(traffic["optimizer"])
     hp = HParams(optimizer=opt, component_lr=lr_policy.server_scaled(
         M, traffic["server_lr_scale"]))
@@ -60,11 +61,15 @@ def _program_round(config, traffic, params, batch):
     return fn(state, batch, None)
 
 
-def _ref_round(family, config, traffic, params, batch):
-    fam = {"mamba2": ref_mamba2, "resnet": ref_resnet}[family]
-    rcfg = train_cell.ref_cfg(config)
-    arg = batch["tokens"] if family == "mamba2" else batch
-    loss, grads = fam.loss_and_grads(params, arg, rcfg)
+def _params(config, traffic, seed):
+    fam = harness.family(config["family"])
+    return weights.make_params(fam, seed, fam.ref_cfg(config),
+                               traffic["clients"])
+
+
+def _ref_round(config, traffic, params, batch):
+    fam = harness.family(config["family"])
+    loss, grads = fam.loss_and_grads(params, batch, fam.ref_cfg(config))
     opt = dict(traffic["optimizer"], server_scale=traffic["server_lr_scale"])
     new, _ = ref_mtsl.apply_opt(opt, params, grads,
                                 ref_mtsl.init_opt(opt, params), 1.0)
@@ -85,11 +90,10 @@ def _close(a, b, tol):
 def test_mamba2_round_matches_program():
     config = _config("tiny-mamba2", dtype="float32")
     traffic = json.loads((FIX / "traffic" / "train-lm.json").read_text())
-    params = weights.make_params("mamba2", 5, train_cell.ref_cfg(config),
-                                 traffic["clients"])
+    params = _params(config, traffic, 5)
     batch = _batch(config, traffic, 5)
     state, metrics = _program_round(config, traffic, params, batch)
-    loss, grads, _ = _ref_round("mamba2", config, traffic, params, batch)
+    loss, grads, _ = _ref_round(config, traffic, params, batch)
     assert abs(float(metrics["loss"]) - float(loss)) <= 1e-5 * abs(float(loss))
     b1 = traffic["optimizer"]["b1"]
     _close(jax.tree.map(lambda m: m / (1 - b1), state.opt_state.mu), grads,
@@ -99,11 +103,10 @@ def test_mamba2_round_matches_program():
 def test_resnet_round_matches_program():
     config = _config("tiny-resnet")
     traffic = json.loads((FIX / "traffic" / "train-image.json").read_text())
-    params = weights.make_params("resnet", 9, train_cell.ref_cfg(config),
-                                 traffic["clients"])
+    params = _params(config, traffic, 9)
     batch = _batch(config, traffic, 9)
     state, metrics = _program_round(config, traffic, params, batch)
-    loss, _, new = _ref_round("resnet", config, traffic, params, batch)
+    loss, _, new = _ref_round(config, traffic, params, batch)
     assert abs(float(metrics["loss"]) - float(loss)) <= 1e-5 * abs(float(loss))
     _close(jax.tree.map(jnp.subtract, state.params, params),
            jax.tree.map(jnp.subtract, new, params), 1e-4)

@@ -17,6 +17,25 @@ the same), and ends the stream at the deadline.
 
 After the window the state is freed and the reference repeats the checked
 steps from the same weights on the same inputs.
+
+What differs between model families comes from the configuration's plug-in
+`families/<family>.py` (harness.Cell.family), which gives:
+
+  ref_cfg(config)          the reference's view of the configuration file
+  program_want(config)     the program config's fields that must equal the
+                           file's (split_layers and the dtypes are added)
+  make_params(key, cfg, M) every weight in float32, in the program's tree
+                           layout, from one key (cfg: ref_cfg's)
+  train_round_flops(config, traffic)   model FLOPs of one round
+  loss_and_grads(params, batch, cfg, cdt)   the reference's summed
+                           per-client loss of a round batch and its gradient
+  UNIT_AXES                optional: logical axes, beyond the client and
+                           layer axes, whose slices are units (weights.py)
+
+A traffic file's optional `mesh`, e.g. {"data": 4}, shards the round over
+that many of the cell's chips (TrainConfig.mesh). The weights are then
+made in place (`placement`), and the reference repeats the gathered
+checked rounds placed the same way.
 """
 from __future__ import annotations
 
@@ -25,32 +44,15 @@ import time
 import types
 
 import check
-import flops
 import harness
 import weights
-
-
-def ref_cfg(config):
-    """The reference's view of a configuration file."""
-    if config["family"] == "mamba2":
-        return {"d_model": config["d_model"], "num_layers": config["n_layer"],
-                "vocab_size": config["vocab_size"],
-                "ssm_state": config["d_state"],
-                "ssm_conv_width": config["d_conv"],
-                "ssm_expand": config["expand"],
-                "ssm_headdim": config["headdim"],
-                "norm_eps": config["norm_epsilon"],
-                "split_layers": config["split_layers"]}
-    return {k: config[k] for k in ("resnet_stages", "image_size",
-                                   "image_channels", "num_classes",
-                                   "split_layers")}
 
 
 def _frozen(v):
     return tuple(map(_frozen, v)) if isinstance(v, list) else v
 
 
-def program_config(config, clients):
+def program_config(config, clients, family):
     """The program's registered config with the file's `program_overrides`,
     checked against the file."""
     from repro.configs import get_config
@@ -58,21 +60,8 @@ def program_config(config, clients):
     pc = get_config(config["registry"], smoke=config.get("smoke", False))
     pc = pc.with_updates(num_clients=clients, **{
         k: _frozen(v) for k, v in config.get("program_overrides", {}).items()})
-    if config["family"] == "mamba2":
-        want = {"d_model": config["d_model"], "num_layers": config["n_layer"],
-                "vocab_size": config["vocab_size"],
-                "ssm_state": config["d_state"],
-                "ssm_conv_width": config["d_conv"],
-                "ssm_expand": config["expand"],
-                "ssm_headdim": config["headdim"],
-                "ssm_chunk": config["chunk_size"],
-                "norm_eps": config["norm_epsilon"]}
-    else:
-        want = {"resnet_stages": tuple(map(tuple, config["resnet_stages"])),
-                "image_size": config["image_size"],
-                "image_channels": config["image_channels"],
-                "num_classes": config["num_classes"]}
-    want.update(split_layers=config["split_layers"], dtype=config["dtype"],
+    want = dict(family.program_want(config),
+                split_layers=config["split_layers"], dtype=config["dtype"],
                 param_dtype=config["param_dtype"])
     bad = {k: (getattr(pc, k), v) for k, v in want.items()
            if getattr(pc, k) != v}
@@ -82,20 +71,56 @@ def program_config(config, clients):
     return pc
 
 
-def check_layout(model, params, clients):
+def layout(cell):
+    """The program's model for the cell, its parameter template
+    (weights.template) and the unit axes read from that."""
+    from repro.models.registry import build_model
+
+    fam, M = cell.family, cell.traffic["clients"]
+    model = build_model(program_config(cell.config, M, fam))
+    tmpl = weights.template(model, M)
+    return model, tmpl, weights.unit_axes(tmpl, getattr(fam, "UNIT_AXES", ()))
+
+
+def check_layout(tmpl, params):
     """The benchmark's weights have the shapes of the program's own."""
     import jax
 
-    from repro.core.algorithms import HParams, get_algorithm
-    from repro.optim import sgd
+    from repro.utils.sharding import strip
 
-    template = jax.eval_shape(lambda: get_algorithm("mtsl").init_state(
-        model, jax.random.PRNGKey(0), clients, HParams(optimizer=sgd(0.1))))
     have = jax.tree.map(lambda x: (x.shape, x.dtype), params)
-    want = jax.tree.map(lambda x: (x.shape, x.dtype), template.params)
+    want = jax.tree.map(lambda x: (x.shape, x.dtype), strip(tmpl))
     if have != want:
         raise SystemExit("bench: the benchmark's weights do not match the "
                          "program's parameter layout")
+
+
+def cell_mesh(cell, devices):
+    """The traffic's mesh over the cell's devices, or None without one."""
+    import math
+
+    spec = cell.traffic.get("mesh")
+    if spec is None:
+        return None
+    if math.prod(spec.values()) != cell.chips:
+        raise SystemExit(f"bench: the mesh {spec} of {cell.name!r} does not "
+                         f"cover its {cell.chips} chips")
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh(tuple(spec.values()), tuple(spec), devices=devices)
+
+
+def placement(mesh):
+    """Where the cell's weights and round inputs are made: the default
+    device (None), or with a mesh, the towers and inputs split by client
+    over all of its devices and the server replicated, so that no chip
+    holds every client's tower."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return {"towers": NamedSharding(mesh, PartitionSpec(mesh.axis_names)),
+            "server": NamedSharding(mesh, PartitionSpec())}
 
 
 def optimizer(spec):
@@ -208,43 +233,45 @@ def program_checks(state, k, n_check, opt, p0_fn, axes, server_scale):
     return out
 
 
-def reference_step(cell, cdt=None):
+def reference_step(cell, axes, cdt=None):
     """The reference's jitted MTSL step: (params, opt_state, batch, k) ->
     (params, opt_state, loss, per-unit gradient norms); params and
     optimizer state are donated."""
     import jax
 
-    from reference import mamba2 as ref_mamba2
     from reference import mtsl as ref_mtsl
-    from reference import resnet as ref_resnet
 
-    config, t = cell.config, cell.traffic
-    rcfg = ref_cfg(config)
-    fam = {"mamba2": ref_mamba2, "resnet": ref_resnet}[config["family"]]
+    fam, t = cell.family, cell.traffic
+    rcfg = fam.ref_cfg(cell.config)
     opt = dict(t["optimizer"], server_scale=t["server_lr_scale"])
+    ax = jax.tree.leaves(axes)
 
     def step(params, opt_state, batch, k):
-        arg = batch["tokens"] if config["family"] == "mamba2" else batch
         with jax.default_matmul_precision("highest"):
-            loss, grads = fam.loss_and_grads(params, arg, rcfg, cdt)
+            loss, grads = fam.loss_and_grads(params, batch, rcfg, cdt)
         params, opt_state = ref_mtsl.apply_opt(opt, params, grads, opt_state, k)
-        ax = jax.tree.leaves(weights.unit_axes(grads))
         return params, opt_state, loss, check.norm_arrays(grads, ax)
 
     return jax.jit(step, donate_argnums=(0, 1)), opt
 
 
-def reference_checks(cell, seed, inputs, cdt=None):
-    """The reference's numbers over the same checked steps."""
+def reference_checks(cell, seed, inputs, axes, cdt=None, mesh=None):
+    """The reference's numbers over the same checked steps. With a mesh,
+    its weights, their optimizer state and the inputs are placed by
+    `placement`, so that a cell too large for one chip's reference fits
+    the cell's chips."""
+    import jax
     import jax.numpy as jnp
 
     from reference import mtsl as ref_mtsl
 
-    config, M = cell.config, cell.traffic["clients"]
-    rcfg = ref_cfg(config)
-    step, opt = reference_step(cell, cdt)
-    params = weights.make_params(config["family"], seed, rcfg, M)
-    axes = weights.unit_axes(params)
+    fam, M = cell.family, cell.traffic["clients"]
+    rcfg = fam.ref_cfg(cell.config)
+    step, opt = reference_step(cell, axes, cdt)
+    where = placement(mesh)
+    params = weights.make_params(fam, seed, rcfg, M, where)
+    if where is not None:
+        inputs = [jax.device_put(b, where["towers"]) for b in inputs]
     paths = check.paths_of(params)
     opt_state = ref_mtsl.init_opt(opt, params)
     losses, grad = [], None
@@ -255,8 +282,8 @@ def reference_checks(cell, seed, inputs, cdt=None):
         if k == 1:
             grad = check.label(paths, gn)
     del opt_state
-    update = check.diff_norms(params, weights.make_params(
-        config["family"], seed, rcfg, M), axes)
+    update = check.diff_norms(
+        params, weights.make_params(fam, seed, rcfg, M, where), axes)
     return {"losses": losses, "grad": grad, "update": update}
 
 
@@ -267,23 +294,23 @@ def run(cell, args, t_start, profile_dir):
     import repro.train.loop as loop
     from repro.core import lr_policy
     from repro.core.mtsl import TrainState
-    from repro.models.registry import build_model
 
     devices = jax.devices()[:cell.chips]
-    config, t = cell.config, cell.traffic
+    config, t, fam = cell.config, cell.traffic, cell.family
     M, n_check = t["clients"], t["check_rounds"]
-    rcfg = ref_cfg(config)
-    model = build_model(program_config(config, M))
+    rcfg = fam.ref_cfg(config)
+    mesh = cell_mesh(cell, devices)
+    where = placement(mesh)
+    model, tmpl, axes = layout(cell)
     opt_spec = t["optimizer"]
     opt = optimizer(opt_spec)
-    params = weights.make_params(config["family"], args.seed, rcfg, M)
-    check_layout(model, params, M)
-    axes = weights.unit_axes(params)
+    params = weights.make_params(fam, args.seed, rcfg, M, where)
+    check_layout(tmpl, params)
     state0 = TrainState(params, opt.init(params), jnp.zeros((), jnp.int32))
-    del params
+    del params, tmpl
 
     def p0():
-        return weights.make_params(config["family"], args.seed, rcfg, M)
+        return weights.make_params(fam, args.seed, rcfg, M, where)
 
     counter = harness.CompileCounter()
     prof = harness.Profile(profile_dir) if args.trace else None
@@ -324,7 +351,8 @@ def run(cell, args, t_start, profile_dir):
                             lr=opt_spec["lr"], log_every=0,
                             seed=args.seed & 0x7FFFFFFF,
                             prefetch=t["prefetch"],
-                            batch_per_client=t["batch_per_client"])
+                            batch_per_client=t["batch_per_client"],
+                            mesh=mesh)
     clr = lr_policy.server_scaled(M, t["server_lr_scale"])
     loop.shard_round_fn = wrapped_round_fn
     try:
@@ -346,12 +374,12 @@ def run(cell, args, t_start, profile_dir):
     mem = harness.memory_peak_bytes(devices)
     del state, probe.marks
     prog = {"losses": [float(x) for x in rec.losses], **rec.prog}
-    ref = reference_checks(cell, args.seed, probe.inputs)
+    ref = reference_checks(cell, args.seed, probe.inputs, axes, mesh=mesh)
     numbers, detail = check.train_numbers(prog, ref)
     control = None
     if getattr(args, "control", False):
-        ctl = reference_checks(cell, args.seed, probe.inputs,
-                               cdt=config["control_dtype"])
+        ctl = reference_checks(cell, args.seed, probe.inputs, axes,
+                               cdt=config["control_dtype"], mesh=mesh)
         control = check.train_numbers(ctl, ref)[0]
 
     per_round = M * t["batch_per_client"] * t.get("seq_len", 1)
@@ -359,7 +387,7 @@ def run(cell, args, t_start, profile_dir):
         kind="train", data=t["data"], cell=cell, chips=cell.chips,
         setup_s=rec.window_t0 - t_start, window_s=t_end - rec.window_t0,
         rounds=rounds, items_per_round=per_round,
-        round_flops=flops.train_round(config, t),
+        round_flops=fam.train_round_flops(config, t),
         device_kind=devices[0].device_kind,
         data_s=feed.data_s, compiles=counter.count, trace=None,
         traced_rounds=rec.traced_rounds)
@@ -367,6 +395,7 @@ def run(cell, args, t_start, profile_dir):
         run_rec.trace = prof.reduce(cell.chips)
     return types.SimpleNamespace(
         run=run_rec, numbers=numbers, control=control, memory=mem,
+        losses=prog["losses"],
         attempted=rounds, failed=0 if final_loss == final_loss else rounds,
         log=(f"rounds in window {rounds}, window {run_rec.window_s:.3f} s, "
              f"compiles in window {counter.count}, losses program "

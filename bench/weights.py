@@ -1,27 +1,27 @@
 """Weights made by the benchmark from the seed, in the program's tree layout.
 
 The program runs and the reference checks the same weights, and neither
-makes them. Each family's maker is one jitted call that builds every leaf
-on the device in float32 from `jax.random` keys folded from the seed, with
-the published initialisation of its architecture:
-
-  mamba2   projections N(0, 1/fan_in); conv taps N(0, 1/width); embedding
-           N(0, 0.02^2); A = -U[1, 16] (A_log = log of it); dt_bias the
-           inverse softplus of dt ~ logU[1e-3, 1e-1]; D and norm scales 1
-           (arXiv:2405.21060, mamba_ssm's Mamba2 defaults)
-  resnet   convs N(0, 2/fan_in) (He); head N(0, 1/fan_in), bias 0
+makes them. Each family's plug-in (`families/<family>.py`) gives a
+`make_params(key, cfg, M)` that builds every leaf in float32 from keys
+split from one key, with the published initialisation of its architecture;
+`make_params` here runs it as one jitted call on the device from the seed.
 
 `unit_axes` says how many leading axes of each leaf index separate units
-(client, then layer) for the per-unit norms that `check.py` compares.
+(client, then layer) for the per-unit norms that `check.py` compares. It
+reads them from the program's own parameter template (`template`), whose
+leaves carry their logical axis names.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+
+# logical axes of the program's parameters that index units: a tower
+# leaf's client axis (core/split.py) and a stacked segment's layer axis
+# (models/stacks.py); a family may name more (its UNIT_AXES)
+UNIT_AXES = ("client", "layers")
 
 
 def seed_key(seed: int):
@@ -30,105 +30,46 @@ def seed_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def _normal(key, shape, std):
+def normal(key, shape, std):
     return jax.random.normal(key, shape, F32) * std
 
 
-def _mamba_layers(key, lead, cfg):
-    d, N, W = cfg["d_model"], cfg["ssm_state"], cfg["ssm_conv_width"]
-    d_in = cfg["ssm_expand"] * d
-    H = d_in // cfg["ssm_headdim"]
-    ks = iter(jax.random.split(key, 16))
+def make_params(family, seed, cfg, M, shardings=None):
+    """Every weight of the cell from the seed; `family` is the cell's
+    plug-in module. On the default device, or made in place where
+    `shardings` (a prefix of the weights' tree) says."""
+    def fn(key):
+        return family.make_params(key, cfg, M)
 
-    def mat(shape, fan_in):
-        return _normal(next(ks), lead + shape, 1.0 / math.sqrt(fan_in))
-
-    dt = jnp.exp(jax.random.uniform(next(ks), lead + (H,), F32,
-                                    math.log(1e-3), math.log(1e-1)))
-    return {"mamba": {
-        "norm": {"scale": jnp.ones(lead + (d,), F32)},
-        "wz": mat((d, d_in), d), "wx": mat((d, d_in), d),
-        "wB": mat((d, N), d), "wC": mat((d, N), d), "wdt": mat((d, H), d),
-        "conv_x": mat((W, d_in), W), "conv_B": mat((W, N), W),
-        "conv_C": mat((W, N), W),
-        "A_log": jnp.log(jax.random.uniform(next(ks), lead + (H,), F32,
-                                            1.0, 16.0)),
-        "D": jnp.ones(lead + (H,), F32),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-        "gate_norm": {"scale": jnp.ones(lead + (d_in,), F32)},
-        "wo": mat((d_in, d), d_in),
-    }}
+    jitted = (jax.jit(fn) if shardings is None
+              else jax.jit(fn, out_shardings=shardings))
+    return jitted(seed_key(seed))
 
 
-def mamba2_params(key, cfg, M):
-    d, V = cfg["d_model"], cfg["vocab_size"]
-    tower_layers = cfg["split_layers"]
-    server_layers = cfg["num_layers"] - tower_layers
-    k = jax.random.split(key, 4)
-    return {
-        "towers": {
-            "embed": {"table": _normal(k[0], (M, V, d), 0.02)},
-            "blocks": {"seg0": {"0": _mamba_layers(k[1], (M, tower_layers),
-                                                   cfg)}},
-        },
-        "server": {
-            "blocks": {"seg0": {"0": _mamba_layers(k[2], (server_layers,),
-                                                   cfg)}},
-            "norm": {"scale": jnp.ones((d,), F32)},
-            "head": {"w": _normal(k[3], (d, V), 1.0 / math.sqrt(d))},
-        },
-    }
+def template(model, M):
+    """The program's MTSL parameters for M clients as shapes, each leaf
+    wrapped with its logical axes (`repro.utils.sharding.Annotated`);
+    nothing is computed."""
+    from repro.core.mtsl import init_state
+    from repro.nn import abstract_params
+
+    with abstract_params():
+        return init_state(model, None, jax.random.PRNGKey(0), M)
 
 
-def _conv(key, lead, k, cin, cout):
-    return {"w": _normal(key, lead + (k, k, cin, cout),
-                         math.sqrt(2.0 / (k * k * cin)))}
+def unit_axes(tmpl, extra=()):
+    """Per leaf of a `template`, its number of leading axes named in
+    UNIT_AXES or `extra`: exactly the leaves that have a client or layer
+    axis count it, whatever their path."""
+    from repro.utils.sharding import Annotated
 
+    names = set(UNIT_AXES) | set(extra)
 
-def _stage(key, lead, cin, cout, nblocks):
-    out = {}
-    for i, kb in enumerate(jax.random.split(key, nblocks)):
-        k1, k2, k3 = jax.random.split(kb, 3)
-        c = cin if i == 0 else cout
-        b = {"conv1": _conv(k1, lead, 3, c, cout),
-             "conv2": _conv(k2, lead, 3, cout, cout)}
-        if c != cout:
-            b["proj"] = _conv(k3, lead, 1, c, cout)
-        out[f"b{i}"] = b
-    return out
+    def count(a):
+        n = 0
+        while n < len(a.axes) and a.axes[n] in names:
+            n += 1
+        return n
 
-
-def resnet_params(key, cfg, M):
-    stages, split = cfg["resnet_stages"], cfg["split_layers"]
-    ks = jax.random.split(key, len(stages) + 2)
-    towers = {"stem": _conv(ks[0], (M,), 3, cfg["image_channels"],
-                            stages[0][0])}
-    server = {}
-    cin = stages[0][0]
-    for s, (cout, nb) in enumerate(stages):
-        lead, side = ((M,), towers) if s < split else ((), server)
-        side[f"stage{s}"] = _stage(ks[s + 1], lead, cin, cout, nb)
-        cin = cout
-    server["head"] = {"w": _normal(ks[-1], (cin, cfg["num_classes"]),
-                                   1.0 / math.sqrt(cin)),
-                      "b": jnp.zeros((cfg["num_classes"],), F32)}
-    return {"towers": towers, "server": server}
-
-
-MAKERS = {"mamba2": mamba2_params, "resnet": resnet_params}
-
-
-def make_params(family, seed, cfg, M):
-    """Every weight of the cell, on the default device, from the seed."""
-    fn = jax.jit(lambda key: MAKERS[family](key, cfg, M))
-    return fn(seed_key(seed))
-
-
-def unit_axes(params):
-    """Leading axes per leaf that index units: the client axis of a tower
-    leaf, and the layer axis of a stacked-block leaf."""
-    def axes(path, _):
-        names = [getattr(p, "key", None) for p in path]
-        return int(names[0] == "towers") + int("seg0" in names)
-
-    return jax.tree_util.tree_map_with_path(axes, params)
+    return jax.tree.map(count, tmpl,
+                        is_leaf=lambda x: isinstance(x, Annotated))
